@@ -158,7 +158,7 @@ def fsdp_primitive() -> List[Row]:
     spec = generate_spec(BenchConfig())
     bufs = ch.device_payload(mesh, spec)
     fn = ch.fsdp_pull_push_fn(mesh, spec.n_buffers)
-    times = bench_lib._timed_loop(fn, bufs, 0.15, 0.4)
+    times, _ = bench_lib._timed_loop(fn, bufs, 0.15, 0.4)
     ici = NETWORKS["tpu_ici"]
     n = mesh.shape[ch.AXIS]
     per_dev = spec.total_bytes
@@ -184,7 +184,7 @@ def extension_dcn_channel() -> List[Row]:
     # intra-"pod" (neighbors 0->1) vs cross-"pod" (0 -> n/2)
     for name, dst in (("intra_pod", 1), ("cross_pod", n // 2)):
         fn = ch.p2p_echo_fn(mesh, spec.n_buffers, src=0, dst=dst)
-        times = bench_lib._timed_loop(fn, bufs, 0.15, 0.4)
+        times, _ = bench_lib._timed_loop(fn, bufs, 0.15, 0.4)
         rows.append(_row(f"ext_dcn/measured/{name}",
                          float(np.mean(times)) * 1e6))
     for net in ("tpu_ici", "tpu_dcn", "rdma_edr"):

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field, replace
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -60,6 +60,10 @@ class BenchStats:
     # the rpc.Tracer the run recorded into (None when untraced) — holds
     # the span trees; export_chrome() writes the Perfetto-loadable JSON
     tracer: Optional[object] = None
+    # the device arrays one iteration returned (device-path families:
+    # the paper benchmarks and the collective transport), so a caller
+    # can check what arrived where
+    outputs: Optional[tuple] = None
 
     def row(self) -> str:
         d = ",".join(f"{k}={v:.6g}" for k, v in self.derived.items())
@@ -67,8 +71,10 @@ class BenchStats:
 
 
 def _timed_loop(fn: Callable, args, warmup_s: float, duration_s: float,
-                min_iters: int = 5) -> List[float]:
-    """Paper protocol: warm up for warmup_s, then measure for duration_s."""
+                min_iters: int = 5) -> Tuple[List[float], tuple]:
+    """Paper protocol: warm up for warmup_s, then measure for duration_s.
+    Returns the iteration times and the first (compiling) call's
+    output."""
     out = fn(*args)
     jax.block_until_ready(out)
     t_end = time.perf_counter() + warmup_s
@@ -80,16 +86,25 @@ def _timed_loop(fn: Callable, args, warmup_s: float, duration_s: float,
         t0 = time.perf_counter()
         jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - t0)
-    return times
+    return times, out
 
 
-def _stats(name, cfg, spec, times, derived, res=None) -> BenchStats:
+def _check_devices(family: str, need: int, have: int) -> None:
+    if have < need:
+        raise RuntimeError(
+            f"{family} needs {need} chips, found {have} "
+            f"{jax.devices()[0].platform} device(s)")
+
+
+def _stats(name, cfg, spec, times, derived, res=None,
+           outputs=None) -> BenchStats:
     a = np.asarray(times)
     st = BenchStats(
         name=name, config=cfg, spec=spec, n_iters=len(a),
         mean_s=float(a.mean()), p50_s=float(np.percentile(a, 50)),
         p95_s=float(np.percentile(a, 95)), min_s=float(a.min()),
-        max_s=float(a.max()), derived=derived, resources=res)
+        max_s=float(a.max()), derived=derived, resources=res,
+        outputs=outputs)
     for net_name, net in NETWORKS.items():
         mode = cfg.resolved_wire_mode
         if name == "p2p_latency":
@@ -139,11 +154,7 @@ def _check_collective_mode(cfg: BenchConfig) -> None:
 def _prep(cfg: BenchConfig, need: int):
     _check_collective_mode(cfg)
     mesh = ch.make_net_mesh()
-    n = mesh.shape[ch.AXIS]
-    if n < need:
-        raise RuntimeError(
-            f"{cfg.benchmark} needs >= {need} devices, have {n}; run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=<n>")
+    _check_devices(cfg.benchmark, need, mesh.shape[ch.AXIS])
     spec = generate_spec(cfg)
     bufs = ch.device_payload(mesh, spec, seed=cfg.seed)
     return mesh, spec, bufs
@@ -154,9 +165,9 @@ def p2p_latency(cfg: BenchConfig) -> BenchStats:
     fn = ch.p2p_echo_fn(mesh, spec.n_buffers,
                         serialized=(cfg.mode == "serialized"))
     with ResourceMonitor() as mon:
-        times = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
+        times, out = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
     return _stats("p2p_latency", cfg, spec, times,
-                  {"rtt_us": float(np.mean(times)) * 1e6}, mon.report)
+                  {"rtt_us": float(np.mean(times)) * 1e6}, mon.report, out)
 
 
 def p2p_bandwidth(cfg: BenchConfig) -> BenchStats:
@@ -164,10 +175,10 @@ def p2p_bandwidth(cfg: BenchConfig) -> BenchStats:
     fn = ch.p2p_send_fn(mesh, spec.n_buffers,
                         serialized=(cfg.mode == "serialized"))
     with ResourceMonitor() as mon:
-        times = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
+        times, out = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
     mbps = spec.total_bytes / np.mean(times) / 1e6
     return _stats("p2p_bandwidth", cfg, spec, times,
-                  {"MBps": float(mbps)}, mon.report)
+                  {"MBps": float(mbps)}, mon.report, out)
 
 
 def ps_throughput(cfg: BenchConfig) -> BenchStats:
@@ -176,10 +187,11 @@ def ps_throughput(cfg: BenchConfig) -> BenchStats:
     fn = ch.ps_round_fn(mesh, spec.n_buffers, cfg.num_ps, cfg.num_workers,
                         serialized=(cfg.mode == "serialized"))
     with ResourceMonitor() as mon:
-        times = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
+        times, out = _timed_loop(fn, bufs, cfg.warmup_s, cfg.duration_s)
     rpcs = ch.rpcs_per_round(cfg.num_ps, cfg.num_workers)
     return _stats("ps_throughput", cfg, spec, times,
-                  {"rpcs_per_s": rpcs / float(np.mean(times))}, mon.report)
+                  {"rpcs_per_s": rpcs / float(np.mean(times))}, mon.report,
+                  out)
 
 
 def _resolve_cluster(cfg: BenchConfig, n_endpoints: int, family: str):
@@ -220,11 +232,8 @@ def _make_fabric(cfg: BenchConfig, spec: PayloadSpec, n_endpoints: int,
     if cfg.transport == "collective":
         _check_collective_mode(cfg)
         mesh = ch.make_net_mesh()
-        if mesh.shape[ch.AXIS] < n_endpoints:
-            raise RuntimeError(
-                f"{family}/collective needs >= {n_endpoints} devices, "
-                f"have {mesh.shape[ch.AXIS]}; run under "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count=<n>")
+        _check_devices(f"{family}/collective", n_endpoints,
+                       mesh.shape[ch.AXIS])
         transport = rpclib.make_transport(
             "collective", n_endpoints, mesh=mesh, spec=spec,
             serialized=serialized, seed=cfg.seed)
@@ -378,6 +387,8 @@ def fully_connected(cfg: BenchConfig) -> BenchStats:
     st.rpc_metrics = metrics.snapshot()
     _attach_trace(st, fabric)
     _cluster_projection(st, cfg, fabric, spec)
+    if cfg.transport == "collective":
+        st.outputs = fabric.transport.outputs
     return st
 
 

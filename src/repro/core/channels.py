@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import serialization as ser
-from repro.core.compat import shard_map_unchecked
 from repro.core.payload import PayloadSpec, materialize
 
 AXIS = "net"
@@ -40,14 +39,22 @@ def make_net_mesh(n_devices: int = 0) -> Mesh:
     return jax.make_mesh((n,), (AXIS,), devices=devs[:n])
 
 
+def host_payload(spec: PayloadSpec, n: int, *, seed: int = 0
+                 ) -> List[np.ndarray]:
+    """One payload row per endpoint: list of (n, size) uint8. Row i is
+    drawn from ``seed + i``, so the bytes a device receives name their
+    sender."""
+    rows = [materialize(spec, seed=seed + i, tpu_align=True)
+            for i in range(n)]
+    return [np.stack([r[j] for r in rows]) for j in range(spec.n_buffers)]
+
+
 def device_payload(mesh: Mesh, spec: PayloadSpec, *, seed: int = 0
                    ) -> List[jax.Array]:
-    """Materialize one payload row per device: list of (N, size) uint8."""
-    n = mesh.shape[AXIS]
-    host = materialize(spec, seed=seed, tpu_align=True)
+    """:func:`host_payload` placed one row per device of the mesh."""
     sharding = NamedSharding(mesh, P(AXIS))
-    return [jax.device_put(np.broadcast_to(b, (n,) + b.shape).copy(),
-                           sharding) for b in host]
+    return [jax.device_put(b, sharding)
+            for b in host_payload(spec, mesh.shape[AXIS], seed=seed)]
 
 
 # ---------------------------------------------------------------------------
@@ -55,9 +62,8 @@ def device_payload(mesh: Mesh, spec: PayloadSpec, *, seed: int = 0
 # ---------------------------------------------------------------------------
 
 def _shmap(mesh, fn, n_in):
-    return shard_map_unchecked(fn, mesh=mesh,
-                               in_specs=tuple([P(AXIS)] * n_in),
-                               out_specs=P(AXIS))
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple([P(AXIS)] * n_in),
+                         out_specs=P(AXIS), check_vma=False)
 
 
 def permute_rounds_fn(mesh: Mesh, n_buffers: int,

@@ -1,4 +1,6 @@
-"""Pallas TPU kernels (validated via interpret=True on CPU):
+"""Pallas TPU kernels (checked against their references with
+interpret=True on CPU, and compiled for a described TPU v5e by
+tests/test_tpu_compile.py):
 
   flash_attention — fused GQA attention (causal/SWA/softcap), the
                     transformer hot spot

@@ -20,14 +20,15 @@ def pack(bufs: Sequence[jax.Array], *, interpret=None
     128-byte lane width; the metadata keeps true sizes for unpack."""
     interpret = _interpret_default() if interpret is None else interpret
     sizes = tuple(int(b.shape[-1]) for b in bufs)
-    padded = [jnp.pad(b.reshape(-1), (0, (-b.shape[-1]) % LANE))
-              for b in bufs]
-    return pack_kernel(padded, interpret=interpret), sizes
+    rows = [jnp.pad(b.reshape(-1), (0, (-b.shape[-1]) % LANE))
+            .reshape(-1, LANE) for b in bufs]
+    return pack_kernel(rows, interpret=interpret).reshape(-1), sizes
 
 
 def unpack(packed: jax.Array, sizes: Sequence[int], *, interpret=None
            ) -> List[jax.Array]:
     interpret = _interpret_default() if interpret is None else interpret
-    padded_sizes = [s + ((-s) % LANE) for s in sizes]
-    outs = unpack_kernel(packed, padded_sizes, interpret=interpret)
-    return [o[:s] for o, s in zip(outs, sizes)]
+    rows = [-(-s // LANE) for s in sizes]
+    outs = unpack_kernel(packed.reshape(-1, LANE), rows,
+                         interpret=interpret)
+    return [o.reshape(-1)[:s] for o, s in zip(outs, sizes)]

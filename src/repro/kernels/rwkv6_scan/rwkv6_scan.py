@@ -38,13 +38,17 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, s0_ref, y_ref, sT_ref,
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)      # (Lc, hs), <= 0
 
-    cum = jnp.cumsum(lw, axis=0)            # inclusive
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum); HIGHEST keeps the sum at f32 precision on the MXU
+    tril = (i_idx <= t_idx).astype(jnp.float32)
+    cum = jax.lax.dot(tril, lw, precision=jax.lax.Precision.HIGHEST)
     cum_tm1 = cum - lw
     # D[t,i,c] = exp(cum_{t-1,c} - cum_{i,c}) for i < t (strict causal)
     dlog = cum_tm1[:, None, :] - cum[None, :, :]
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    mask = (i_idx < t_idx)[:, :, None]
+    mask = (jax.lax.broadcasted_iota(jnp.int32, dlog.shape, 1)
+            < jax.lax.broadcasted_iota(jnp.int32, dlog.shape, 0))
     d = jnp.exp(jnp.where(mask, dlog, NEG_INF))
     a = jnp.sum(r[:, None, :] * k[None, :, :] * d, axis=-1)   # (Lc, Lc)
 
@@ -53,8 +57,9 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, s0_ref, y_ref, sT_ref,
     y_inter = jax.lax.dot(r * jnp.exp(cum_tm1), st)
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    decay_out = jnp.exp(cum[-1:, :] - cum)   # (Lc, hs), <= 1
-    state_scr[...] = st * jnp.exp(cum[-1, :])[:, None] + jax.lax.dot(
+    cum_last = cum[chunk - 1:chunk, :]       # (1, hs)
+    decay_out = jnp.exp(cum_last - cum)      # (Lc, hs), <= 1
+    state_scr[...] = st * jnp.exp(cum_last).T + jax.lax.dot(
         (k * decay_out).T, v)
 
     @pl.when(ci == n_chunks - 1)

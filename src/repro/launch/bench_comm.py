@@ -318,6 +318,8 @@ def _cell(row: dict, col: str) -> str:
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="TF-gRPC-Bench micro-benchmark suite (paper Table 2)")
     ap.add_argument("--benchmark", default="p2p_latency",

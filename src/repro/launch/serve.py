@@ -44,14 +44,12 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced_config
-from repro.models import init_params
-from repro.parallel.sharding import make_ctx
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.engine import (DISPATCH_POLICIES, ServeConfig,
-                                ServeEngine)
+                                ServeEngine, build_engine)
 from repro.serve.scheduler import SCHED_POLICIES
 
 
@@ -127,6 +125,7 @@ def _serve_cluster_rounds(engine: ServeEngine, cluster, args,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -216,9 +215,7 @@ def main() -> None:
     acfg = (get_reduced_config(args.arch) if args.reduced
             else get_config(args.arch))
     assert not acfg.model.is_encoder, "encoder archs do not serve decode"
-    ctx = make_ctx(acfg, None)
-    params = init_params(jax.random.PRNGKey(0), acfg)
-    engine = ServeEngine(ctx, acfg, params, ServeConfig(
+    engine = build_engine(acfg, ServeConfig(
         max_seq=args.prompt_len + args.new_tokens + 8,
         max_new_tokens=args.new_tokens, temperature=args.temperature))
 

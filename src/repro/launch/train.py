@@ -18,12 +18,14 @@ import jax
 
 from repro.configs import get_config, get_reduced_config, get_shape
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.parallel.sharding import make_ctx
 from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")

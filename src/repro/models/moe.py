@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.core.compat import shard_map_unchecked
 from repro.models.layers import activation, dense_init
 from repro.parallel.sharding import ParallelCtx
 
@@ -172,8 +171,8 @@ def apply_moe(ctx: ParallelCtx, cfg: ModelConfig, moe: MoEConfig, p: dict,
                 {k2: ctx.spec(*la[k2]) for k2 in p})
     out_specs = (P(b_ax, None, None), P())
 
-    @shard_map_unchecked(mesh=ctx.mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    @functools.partial(jax.shard_map, mesh=ctx.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def sharded(xl, pl):
         Bl, Sl, _ = xl.shape
         if ctx.fsdp:  # PS pull: all-gather weight shards over the data axes

@@ -36,6 +36,8 @@ class CollectiveTransport(Transport):
         self.spec = spec
         self.serialized = serialized
         self.bufs = ch.device_payload(mesh, spec, seed=seed)
+        #: the device arrays the last flight's program returned
+        self.outputs = None
         self._fns: Dict[Tuple[Tuple[Tuple[int, int], ...], ...],
                         Callable] = {}
 
@@ -55,8 +57,7 @@ class CollectiveTransport(Transport):
         perms = tuple(tuple((m.src, m.dst) for m in rnd) for rnd in rounds)
         fn = self._fn(perms)
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*self.bufs))
+        self.outputs = jax.block_until_ready(fn(*self.bufs))
         elapsed = time.perf_counter() - t0
-        del out
         return Delivery(list(messages), elapsed, len(rounds),
                         modeled=False)

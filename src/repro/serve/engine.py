@@ -38,6 +38,7 @@ endpoints generate concurrently over per-link-priced cluster routes.
 """
 from __future__ import annotations
 
+import dataclasses
 import warnings
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -49,7 +50,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.launch import steps as steps_lib
 from repro.models import model as M
-from repro.parallel.sharding import ParallelCtx
+from repro.parallel.sharding import ParallelCtx, make_ctx
 from repro.rpc.interceptors import (ClientInterceptor,
                                     MetricsInterceptor,
                                     is_resource_exhausted)
@@ -323,6 +324,26 @@ class ServeEngine:
                                      serialized=serialized)
                  for w in workers}
         return fabric, stubs
+
+
+def serving_config(acfg: ArchConfig) -> ArchConfig:
+    """``acfg`` with its weights held in the compute dtype. The forward
+    pass casts float32 weights to the compute dtype on every call, so a
+    float32 copy only doubles the weight memory a chip must hold.
+    Reduced configs compute in float32 and are returned unchanged."""
+    return acfg.replace(train=dataclasses.replace(
+        acfg.train, param_dtype=acfg.train.compute_dtype))
+
+
+def build_engine(acfg: ArchConfig, cfg: ServeConfig, *, seed: int = 0
+                 ) -> ServeEngine:
+    """A single-device engine over random weights made from ``seed``, in
+    the compute dtype (:func:`serving_config`). Initialisation is jitted
+    so the weights are written once, in their final dtype."""
+    acfg = serving_config(acfg)
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), acfg)
+    return ServeEngine(make_ctx(acfg, None), acfg, params, cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -129,3 +129,25 @@ def test_pack_matches_ref_when_aligned():
             for s in (128, 512, 1024, 128)]
     packed, _ = pack(bufs)
     assert bool(jnp.array_equal(packed, pack_ref(bufs)))
+
+
+@pytest.mark.parametrize("rows", [
+    (1, 70, 33, 100, 2),      # header row, then buffers across seams
+    (1, 31, 96, 7, 300),      # buffers inside, across and over blocks
+    (3, 200, 1, 1, 97),
+    (32, 32, 32),             # every buffer tile-aligned
+])
+def test_pack_kernel_multi_block_matches_concat(rows):
+    """Small blocks put buffers across block seams and give long
+    buffers interior blocks: the pipelined paths that real payloads
+    take, at interpret-mode sizes."""
+    from repro.kernels.payload_pack.payload_pack import (pack_kernel,
+                                                         unpack_kernel)
+    rng = np.random.default_rng(sum(rows))
+    bufs = [rng.integers(0, 255, (r, 128), dtype=np.uint8) for r in rows]
+    packed = pack_kernel([jnp.asarray(b) for b in bufs], block=32,
+                         interpret=True)
+    assert np.array_equal(np.asarray(packed), np.concatenate(bufs))
+    outs = unpack_kernel(packed, rows, block=32, interpret=True)
+    for a, b in zip(bufs, outs):
+        assert np.array_equal(a, np.asarray(b))

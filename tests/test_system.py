@@ -148,3 +148,80 @@ def test_sliding_window_actually_limits_context():
     np.testing.assert_allclose(np.asarray(h1[:, -1]),
                                np.asarray(h2[:, -1]), atol=1e-6)
     del base
+
+
+def test_serving_weights_in_compute_dtype():
+    """Full-width serving holds bf16 weights (a float32 copy of
+    qwen1.5-4b does not fit one chip); reduced configs stay float32."""
+    from repro.serve.engine import serving_config
+    full = serving_config(get_config("qwen1.5-4b"))
+    assert full.train.param_dtype == full.train.compute_dtype == "bfloat16"
+    shapes = jax.eval_shape(lambda k: init_params(k, full),
+                            jax.random.PRNGKey(0))
+    nbytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(shapes))
+    assert nbytes < 8.5e9 and {s.dtype for s in jax.tree.leaves(shapes)} \
+        == {jnp.dtype(jnp.bfloat16)}
+    small = get_reduced_config("qwen1.5-4b")
+    assert serving_config(small) == small
+
+
+def test_build_engine_serves_rpc_equal_to_direct():
+    """The serve CLI's engine: tokens served over the loopback fabric
+    equal the engine's direct path, request by request."""
+    from repro.serve.engine import (build_engine, rpc_generate_stream,
+                                    serve_stub)
+    cfg = get_reduced_config("qwen1.5-4b")
+    eng = build_engine(cfg, ServeConfig(max_seq=24, max_new_tokens=4))
+    _, channel = eng.serve_loopback()
+    rng = np.random.default_rng(0)
+    for _ in range(2):
+        prompts = rng.integers(0, cfg.model.vocab_size, (2, 8),
+                               dtype=np.int32)
+        direct = eng.generate(prompts)
+        assert np.array_equal(rpc_generate_stream(channel, prompts), direct)
+        assert np.array_equal(
+            serve_stub(channel).generate((prompts, 0)).result(), direct)
+
+
+@pytest.mark.parametrize("benchmark", ["ps_throughput", "fully_connected"])
+def test_bench_names_the_chips_it_needs(benchmark):
+    """One endpoint more than this process has devices: the error says
+    how many chips the benchmark needs and what it found."""
+    from repro.configs.tfgrpc_bench import BenchConfig
+    from repro.core import bench
+    have = len(jax.devices())
+    need = have + 1
+    cfg = BenchConfig(benchmark=benchmark, transport="collective",
+                      num_ps=1, num_workers=have, iovec_count=2,
+                      categories=("small",))
+    if benchmark == "fully_connected":
+        cfg = dataclasses.replace(cfg, num_workers=need)
+    with pytest.raises(RuntimeError) as e:
+        bench.run(cfg)
+    msg = str(e.value)
+    assert f"needs {need} chips" in msg
+    assert f"found {have} {jax.devices()[0].platform} device(s)" in msg
+    assert "xla_force_host_platform_device_count" not in msg
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = compile_cache.use_compile_cache()
+        if env_dir is None:
+            root = compile_cache.CHECKOUT_ROOT
+            assert (root / "src" / "repro").is_dir()
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
