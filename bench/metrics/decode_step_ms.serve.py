@@ -1,0 +1,7 @@
+"""Device time per decode call: the mean duration of the program
+``jit_decode`` on the chip in the traced stretch."""
+
+
+def read(run):
+    times = run.trace.module_times("jit_decode") if run.trace else []
+    return sum(times) / len(times) * 1e3 if times else None
