@@ -26,7 +26,9 @@ and per-endpoint interceptor metrics:
 (loopback or cluster) and exports every request's span tree — queue /
 credit-stall / wire / server / reply phases, retries and shard
 failovers included, plus the scheduler's waiting / prefill / decode /
-preempted request phases — as Chrome trace-event JSON for Perfetto.
+preempted request phases and the serving thread's host spans
+(``rpc.pump`` / ``serve.step`` / ``serve.decode`` and their parts) —
+as Chrome trace-event JSON for Perfetto.
 
 Each served endpoint runs a continuous-batching scheduler
 (``repro.serve.scheduler``): ``--max-batch N`` caps concurrent decodes
@@ -119,7 +121,7 @@ def _serve_cluster_rounds(engine: ServeEngine, cluster, args,
         st = sched.stats()
         print(f"scheduler [{ep}]: "
               f"admitted={st['admitted']} finished={st['finished']} "
-              f"preempted={st['preempted']} requeued={st['requeued']} "
+              f"preempted={st['preempted']} "
               f"peak_running={st['peak_running']}")
     _export_trace(tracer, args.trace)
 
@@ -260,7 +262,6 @@ def main() -> None:
         st = sched.stats()
         print(f"scheduler [{ep}]: admitted={st['admitted']} "
               f"finished={st['finished']} preempted={st['preempted']} "
-              f"requeued={st['requeued']} "
               f"peak_running={st['peak_running']}")
     if args.trace:
         _export_trace(tracer, args.trace)

@@ -57,7 +57,11 @@ fabric clock — queue/credit_stall/wire/server/reply/backoff phases on
 the client track, admit/shed/handler spans on the server tracks — with
 the trace id stamped into the frame header at flight departure
 alongside the budget, so spans stay attributed across cluster
-endpoints, retries, and failover re-routes.
+endpoints, retries, and failover re-routes. The flush loop's own
+phases are host spans (``tracing.host_span``): ``rpc.pump`` around
+each pull of a pumped stream, ``rpc.deliver`` around each
+``transport.deliver``, ``rpc.complete`` around landing what a flight
+delivered.
 
 Transports with ``dispatches=False`` (the collective transport) are pure
 exchange datapaths: delivery itself completes the call and the reply
@@ -80,7 +84,7 @@ from repro.rpc.interceptors import (RESOURCE_EXHAUSTED, TRANSIENT_PREFIX,
                                     CallContext, ClientInterceptor,
                                     ResourceExhausted, ServerContext,
                                     ServerInterceptor, TransientError)
-from repro.rpc.tracing import Tracer
+from repro.rpc.tracing import Tracer, host_span
 from repro.rpc.transport import Message, Transport
 
 
@@ -1291,7 +1295,9 @@ class RpcFabric:
                 for m in stamped:
                     if not m.frame.is_reply:
                         self.tracer.on_depart(m.frame.call_id, t_send)
-            delivery = self.transport.deliver(stamped)
+            with host_span("rpc.deliver", self.tracer,
+                           messages=len(stamped)):
+                delivery = self.transport.deliver(stamped)
             rep.flights += 1
             rep.rounds += delivery.rounds
             rep.messages += len(delivery.messages)
@@ -1301,109 +1307,13 @@ class RpcFabric:
                 for m in delivery.messages:
                     if not (m.frame.flags & framing.FLAG_FAULT):
                         self.tracer.on_wire(m, t_send, t_arrive)
-            replies: List[Message] = []
-            dead: Set[int] = set()      # calls killed by a link fault
-            # per-dst call_ids landed this flight: the queue-depth unit
-            # is CALLS (a stream's chunks are one call's arrivals)
-            arrivals: Dict[int, Set[int]] = {}
-            for m in delivery.messages:
-                if m.frame.flags & framing.FLAG_FAULT:
-                    dead.update(self._on_link_fault(m))
-                    continue
-                if m.frame.call_id in dead:
-                    # a straggler of a call a link fault already killed
-                    # this flight: consume it, refund its credits, and
-                    # never let it re-create server-side stream state
-                    self._refund_message(m)
-                    continue
-                if m.frame.is_reply:
-                    # server->client stream chunk riding a main flight
-                    self._on_client_chunk(m)
-                    continue
-                call = self._calls.get(m.frame.call_id)
-                handle = self._handles.get(m.frame.call_id)
-                if not self.transport.dispatches:
-                    # exchange datapath: delivery IS completion — a
-                    # stream's call completes when its END lands, so
-                    # deadlines/metrics cover the whole stream
-                    self._grant(m)
-                    if call is not None and not call.done \
-                            and (not m.frame.is_stream
-                                 or m.frame.stream_end):
-                        self._complete(call, m.frame, "sent")
-                    if handle is not None and m.frame.stream_end:
-                        self._finish_handle(handle)
-                    continue
-                srv = self.servers.get(m.dst)
-                if srv is None:
-                    self._grant(m)
-                    err = f"no server at endpoint {m.dst}"
-                    if call is not None and not call.done:
-                        self._complete(call, None, "error", error=err)
-                    if handle is not None and not handle.done:
-                        self._finish_handle(handle, error=err)
-                    continue
-                # the server's view of the propagated deadline: the
-                # budget the frame left with, minus what the wire ate
-                deadline = (t_send + m.frame.budget_us / 1e6
-                            if m.frame.budget_us else None)
-                cid = m.frame.call_id
-                landed = arrivals.setdefault(m.dst, set())
-                landed.add(cid)
-                # queue depth = calls landed on this endpoint so far
-                # this flight (including this one) + partial input
-                # streams still open from EARLIER flights. Open pumps
-                # are NOT counted: a pump is a call that was already
-                # admitted and is now delivering results, so counting
-                # it would starve unary traffic behind every long
-                # decode (pump load reaches dispatch policies via the
-                # scheduler gauges instead).
-                depth = len(landed) \
-                    + sum(1 for k in srv._streams if k not in landed) \
-                    + sum(1 for k in srv._bidi_seq if k not in landed)
-                if self.tracer is not None:
-                    self.tracer.on_server(cid, self.now())
-                outs = srv.dispatch(m.frame, deadline_s=deadline,
-                                    queue_depth=depth)
-                self._emit(Event(m.frame.call_id, "received",
-                                 payload=_spec_only(m.frame)))
-                plain = [o for o in outs if not o.is_stream]
-                chunks = [o for o in outs if o.is_stream]
-                pump = srv._pumps.get(cid)
-                if pump is not None and pump.channel_key is None:
-                    pump.channel_key = (m.src, m.dst,
-                                        m.frame.wire_mode)
-                if self.tracer is not None:
-                    self.tracer.on_dispatched(
-                        cid, self.now(),
-                        replying=bool(plain or chunks)
-                        or pump is not None)
-                if plain:
-                    # request credits return when the reply lands
-                    self._awaiting_grant.setdefault(m.frame.call_id,
-                                                    []).append(m)
-                    replies.extend(Message(m.dst, m.src, o)
-                                   for o in plain)
-                else:
-                    # stream-kind input (or one-way): receipt is
-                    # consumption — forward credits return now. A
-                    # one-way STREAM call completes only when its END
-                    # chunk is consumed, keeping the call context (and
-                    # its deadline) live for the whole stream
-                    self._grant(m)
-                    if call is not None and m.frame.one_way \
-                            and not call.done \
-                            and (not m.frame.is_stream
-                                 or m.frame.stream_end):
-                        self._complete(call, None, "sent")
-                for o in chunks:
-                    ch = self._channels.get((m.src, m.dst,
-                                             m.frame.wire_mode))
-                    assert ch is not None
-                    self._offer_chunk(ch, o)
+            with host_span("rpc.complete", self.tracer):
+                replies = self._land(delivery.messages, t_send)
             if replies:
                 t_rsend = self.now()
-                rdel = self.transport.deliver(replies)
+                with host_span("rpc.deliver", self.tracer,
+                               messages=len(replies)):
+                    rdel = self.transport.deliver(replies)
                 rep.flights += 1
                 rep.rounds += rdel.rounds
                 rep.replies += len(rdel.messages)
@@ -1413,58 +1323,170 @@ class RpcFabric:
                     for m in rdel.messages:
                         if not (m.frame.flags & framing.FLAG_FAULT):
                             self.tracer.on_wire(m, t_rsend, t_rarr)
-                for m in rdel.messages:
-                    # grant the REQUEST's credits (reply size differs);
-                    # even for a LOST reply — the server consumed the
-                    # request regardless
-                    reqs = self._awaiting_grant.get(m.frame.call_id)
-                    if reqs:
-                        self._grant(reqs.pop(0))
-                        if not reqs:
-                            del self._awaiting_grant[m.frame.call_id]
-                    if m.frame.flags & framing.FLAG_FAULT:
-                        # the reply was lost to an injected link fault:
-                        # the call fails transiently (a retry re-runs
-                        # the handler — at-least-once, like gRPC)
-                        if self.tracer is not None:
-                            self.tracer.on_fault(m, self.now())
-                        ctx = self._ctx.get(m.frame.call_id)
-                        if ctx is not None:
-                            self._cancel(ctx, LINK_FAULT, kind="error")
-                        continue
-                    is_err = bool(m.frame.flags & framing.FLAG_ERROR)
-                    err = None
-                    if is_err:
-                        err = bytes(m.frame.bufs[0]).decode(
-                            errors="replace") if m.frame.bufs else "error"
-                        # a rejected/shed stream call's remaining chunks
-                        # are already doomed: purge them so they cannot
-                        # re-create server-side state no END cleans up
-                        self._purge_call(m.frame.call_id)
-                    # server-shed work is a deadline outcome, not a
-                    # generic error — metrics must count it as such
-                    err_kind = ("deadline_exceeded"
-                                if err and DEADLINE_EXCEEDED in err
-                                else "error")
-                    handle = self._handles.get(m.frame.call_id)
-                    if handle is not None and not handle.done:
-                        # stream request answered with a plain (error)
-                        # reply — fail the handle
-                        self._finish_handle(
-                            handle, error=err or "protocol error",
-                            kind=err_kind if is_err else None)
-                    call = self._calls.get(m.frame.call_id)
-                    if call is None or call.done:
-                        continue
-                    if is_err:
-                        self._complete(call, m.frame, err_kind,
-                                       error=err)
-                    else:
-                        self._complete(call, m.frame, "replied")
+                with host_span("rpc.complete", self.tracer):
+                    self._land_replies(rdel.messages)
             self._admit_backlog()
             self._pump_gates()
         rep.wall_s = time.perf_counter() - t0
         return rep
+
+    def _land(self, messages: List[Message], t_send: float
+              ) -> List[Message]:
+        """Hand each message of a delivered flight to its receiver:
+        stream chunks to their client handles, requests to their
+        servers (dispatch). Returns the replies the servers made."""
+        replies: List[Message] = []
+        dead: Set[int] = set()      # calls killed by a link fault
+        # per-dst call_ids landed this flight: the queue-depth unit
+        # is CALLS (a stream's chunks are one call's arrivals)
+        arrivals: Dict[int, Set[int]] = {}
+        for m in messages:
+            if m.frame.flags & framing.FLAG_FAULT:
+                dead.update(self._on_link_fault(m))
+                continue
+            if m.frame.call_id in dead:
+                # a straggler of a call a link fault already killed
+                # this flight: consume it, refund its credits, and
+                # never let it re-create server-side stream state
+                self._refund_message(m)
+                continue
+            if m.frame.is_reply:
+                # server->client stream chunk riding a main flight
+                self._on_client_chunk(m)
+                continue
+            call = self._calls.get(m.frame.call_id)
+            handle = self._handles.get(m.frame.call_id)
+            if not self.transport.dispatches:
+                # exchange datapath: delivery IS completion — a
+                # stream's call completes when its END lands, so
+                # deadlines/metrics cover the whole stream
+                self._grant(m)
+                if call is not None and not call.done \
+                        and (not m.frame.is_stream
+                             or m.frame.stream_end):
+                    self._complete(call, m.frame, "sent")
+                if handle is not None and m.frame.stream_end:
+                    self._finish_handle(handle)
+                continue
+            srv = self.servers.get(m.dst)
+            if srv is None:
+                self._grant(m)
+                err = f"no server at endpoint {m.dst}"
+                if call is not None and not call.done:
+                    self._complete(call, None, "error", error=err)
+                if handle is not None and not handle.done:
+                    self._finish_handle(handle, error=err)
+                continue
+            # the server's view of the propagated deadline: the
+            # budget the frame left with, minus what the wire ate
+            deadline = (t_send + m.frame.budget_us / 1e6
+                        if m.frame.budget_us else None)
+            cid = m.frame.call_id
+            landed = arrivals.setdefault(m.dst, set())
+            landed.add(cid)
+            # queue depth = calls landed on this endpoint so far
+            # this flight (including this one) + partial input
+            # streams still open from EARLIER flights. Open pumps
+            # are NOT counted: a pump is a call that was already
+            # admitted and is now delivering results, so counting
+            # it would starve unary traffic behind every long
+            # decode (pump load reaches dispatch policies via the
+            # scheduler gauges instead).
+            depth = len(landed) \
+                + sum(1 for k in srv._streams if k not in landed) \
+                + sum(1 for k in srv._bidi_seq if k not in landed)
+            if self.tracer is not None:
+                self.tracer.on_server(cid, self.now())
+            outs = srv.dispatch(m.frame, deadline_s=deadline,
+                                queue_depth=depth)
+            self._emit(Event(m.frame.call_id, "received",
+                             payload=_spec_only(m.frame)))
+            plain = [o for o in outs if not o.is_stream]
+            chunks = [o for o in outs if o.is_stream]
+            pump = srv._pumps.get(cid)
+            if pump is not None and pump.channel_key is None:
+                pump.channel_key = (m.src, m.dst,
+                                    m.frame.wire_mode)
+            if self.tracer is not None:
+                self.tracer.on_dispatched(
+                    cid, self.now(),
+                    replying=bool(plain or chunks)
+                    or pump is not None)
+            if plain:
+                # request credits return when the reply lands
+                self._awaiting_grant.setdefault(m.frame.call_id,
+                                                []).append(m)
+                replies.extend(Message(m.dst, m.src, o)
+                               for o in plain)
+            else:
+                # stream-kind input (or one-way): receipt is
+                # consumption — forward credits return now. A
+                # one-way STREAM call completes only when its END
+                # chunk is consumed, keeping the call context (and
+                # its deadline) live for the whole stream
+                self._grant(m)
+                if call is not None and m.frame.one_way \
+                        and not call.done \
+                        and (not m.frame.is_stream
+                             or m.frame.stream_end):
+                    self._complete(call, None, "sent")
+            for o in chunks:
+                ch = self._channels.get((m.src, m.dst,
+                                         m.frame.wire_mode))
+                assert ch is not None
+                self._offer_chunk(ch, o)
+        return replies
+
+    def _land_replies(self, messages: List[Message]) -> None:
+        """Complete the calls a delivered flight of replies answers."""
+        for m in messages:
+            # grant the REQUEST's credits (reply size differs);
+            # even for a LOST reply — the server consumed the
+            # request regardless
+            reqs = self._awaiting_grant.get(m.frame.call_id)
+            if reqs:
+                self._grant(reqs.pop(0))
+                if not reqs:
+                    del self._awaiting_grant[m.frame.call_id]
+            if m.frame.flags & framing.FLAG_FAULT:
+                # the reply was lost to an injected link fault:
+                # the call fails transiently (a retry re-runs
+                # the handler — at-least-once, like gRPC)
+                if self.tracer is not None:
+                    self.tracer.on_fault(m, self.now())
+                ctx = self._ctx.get(m.frame.call_id)
+                if ctx is not None:
+                    self._cancel(ctx, LINK_FAULT, kind="error")
+                continue
+            is_err = bool(m.frame.flags & framing.FLAG_ERROR)
+            err = None
+            if is_err:
+                err = bytes(m.frame.bufs[0]).decode(
+                    errors="replace") if m.frame.bufs else "error"
+                # a rejected/shed stream call's remaining chunks
+                # are already doomed: purge them so they cannot
+                # re-create server-side state no END cleans up
+                self._purge_call(m.frame.call_id)
+            # server-shed work is a deadline outcome, not a
+            # generic error — metrics must count it as such
+            err_kind = ("deadline_exceeded"
+                        if err and DEADLINE_EXCEEDED in err
+                        else "error")
+            handle = self._handles.get(m.frame.call_id)
+            if handle is not None and not handle.done:
+                # stream request answered with a plain (error)
+                # reply — fail the handle
+                self._finish_handle(
+                    handle, error=err or "protocol error",
+                    kind=err_kind if is_err else None)
+            call = self._calls.get(m.frame.call_id)
+            if call is None or call.done:
+                continue
+            if is_err:
+                self._complete(call, m.frame, err_kind,
+                               error=err)
+            else:
+                self._complete(call, m.frame, "replied")
 
     def _gated_chunks(self) -> int:
         return sum(len(ch.rx_gate) for ch in self._channels.values())
@@ -1488,7 +1510,9 @@ class RpcFabric:
                 if any(m.frame.call_id == cid
                        for m, _ in ch.rx_gate.items()):
                     continue
-                for o in srv.pump_one(cid):
+                with host_span("rpc.pump", self.tracer, call=cid):
+                    outs = srv.pump_one(cid)
+                for o in outs:
                     self._offer_chunk(ch, o)
 
     def _pump_gates(self, force_one: bool = False) -> int:

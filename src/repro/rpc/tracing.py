@@ -28,6 +28,13 @@ reading, so per-call phase durations sum exactly to the end-to-end
 latency. That is the invariant the hypothesis tier asserts and the
 per-phase breakdown ``bench_comm --json`` reports.
 
+*Host spans* (:func:`host_span`) mark work nested on the serving
+thread — the fabric's pump/deliver/complete phases, the scheduler's
+step, the engine's prefill and decode calls and their parts. Each one
+is a ``jax.profiler.TraceAnnotation`` while the profiler runs, so it
+lands on the device trace's clock, and with a tracer given it is also
+a ``host`` span here, on the tracer's clock, on its own track.
+
 Export: :meth:`Tracer.export_chrome` writes Chrome trace-event JSON
 (one track per endpoint, loadable at https://ui.perfetto.dev);
 :meth:`Tracer.phase_breakdown` aggregates phase totals per method.
@@ -36,6 +43,7 @@ This module never reads wall time itself (CI telemetry-clock gate).
 from __future__ import annotations
 
 import json
+import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -45,11 +53,16 @@ PHASES = ("queue", "credit_stall", "wire", "server", "reply", "backoff")
 #: trace_id is a uint32 header word (0 = untraced)
 MAX_TRACE_ID = 0xFFFFFFFF
 
+#: the Chrome export's track for host spans (endpoints are >= 0)
+HOST_TRACK = -1
+
 
 @dataclass
 class Span:
     """One node of a call's span tree. ``end_s is None`` while open;
-    ``category`` is one of call/attempt/phase/wire/server/fault."""
+    ``category`` is one of call/attempt/phase/wire/server/fault, or
+    host for a :func:`host_span` (whose parent is the host span it
+    nests in, not the call's tree)."""
     span_id: int
     trace_id: int
     name: str
@@ -111,6 +124,7 @@ class Tracer:
         self._spans: List[Span] = []
         self._by_call: Dict[int, _CallState] = {}
         self._by_trace: Dict[int, _CallState] = {}
+        self._host: List[Span] = []      # open host spans, innermost last
         self._next_trace = 1
         self._next_span = 1
 
@@ -139,7 +153,8 @@ class Tracer:
               attrs: Optional[Dict[str, Any]] = None) -> Span:
         s = Span(self._next_span, trace_id, name, category, start_s,
                  parent_id=parent.span_id if parent is not None else None,
-                 endpoint=endpoint, attrs=attrs or {})
+                 endpoint=endpoint,
+                 attrs=attrs if attrs is not None else {})
         self._next_span += 1
         self._spans.append(s)
         if parent is not None:
@@ -303,6 +318,29 @@ class Tracer:
         if error:
             st.root.attrs["error"] = error
 
+    # host spans (host_span) -------------------------------------------
+    def open_host(self, name: str, attrs: Dict[str, Any]) -> Optional[Span]:
+        """Open a host span inside the innermost open one. Its trace id
+        is that of the call named by a ``call`` attr, else its
+        parent's, else 0. At the span cap nothing is recorded (the cap
+        bounds memory; ``dropped`` counts calls only)."""
+        if len(self._spans) >= self.max_spans:
+            return None
+        parent = self._host[-1] if self._host else None
+        st = self._by_call.get(attrs["call"]) if "call" in attrs else None
+        trace_id = (st.root.trace_id if st is not None else
+                    parent.trace_id if parent is not None else 0)
+        s = self._span(name, "host", trace_id, self.now(), parent=parent,
+                       attrs=attrs)
+        self._host.append(s)
+        return s
+
+    def close_host(self, span: Optional[Span]) -> None:
+        if span is None:
+            return
+        span.end_s = self.now()
+        self._host.remove(span)
+
     # queries ----------------------------------------------------------
     def spans(self) -> List[Span]:
         return list(self._spans)
@@ -321,6 +359,7 @@ class Tracer:
         self._spans.clear()
         self._by_call.clear()
         self._by_trace.clear()
+        self._host.clear()
         self.dropped = 0
 
     def phase_breakdown(self) -> Dict[str, Dict[str, Any]]:
@@ -346,8 +385,8 @@ class Tracer:
     # export -----------------------------------------------------------
     def chrome_events(self) -> List[Dict[str, Any]]:
         """Chrome trace-event list: one pid, one tid (track) per
-        endpoint, complete ("X") events in microseconds. Open spans are
-        skipped."""
+        endpoint and one (:data:`HOST_TRACK`) for host spans, complete
+        ("X") events in microseconds. Open spans are skipped."""
         events: List[Dict[str, Any]] = [{
             "ph": "M", "name": "process_name", "pid": 0, "tid": 0,
             "args": {"name": "rpc-fabric"}}]
@@ -358,17 +397,21 @@ class Tracer:
                            "tid": ep,
                            "args": {"name": f"endpoint "
                                             f"{self._ep_name(ep)}"}})
+        if any(s.category == "host" for s in self._spans):
+            events.append({"ph": "M", "name": "thread_name", "pid": 0,
+                           "tid": HOST_TRACK,
+                           "args": {"name": "host (serving thread)"}})
         for s in self._spans:
             if not s.closed:
                 continue
             args = dict(s.attrs)
             args["trace_id"] = s.trace_id
+            tid = (HOST_TRACK if s.category == "host" else
+                   s.endpoint if s.endpoint is not None else 0)
             events.append({
                 "ph": "X", "name": s.name, "cat": s.category,
                 "ts": s.start_s * 1e6, "dur": s.duration_s * 1e6,
-                "pid": 0, "tid": s.endpoint if s.endpoint is not None
-                else 0,
-                "args": args})
+                "pid": 0, "tid": tid, "args": args})
         return events
 
     def export_chrome(self, path) -> None:
@@ -383,4 +426,77 @@ class Tracer:
                 json.dump(doc, f)
 
 
-__all__ = ["MAX_TRACE_ID", "PHASES", "Span", "Tracer"]
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while the profiler records,
+    else None. Without jax imported no profiler can run, and this
+    module does not import it (``repro.rpc`` stays jax-free)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
+
+
+class _NoSpan:
+    """What :func:`host_span` returns with no tracer and no profiler."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _HostSpan:
+    __slots__ = ("name", "tracer", "attrs", "_annotation", "_me", "_span")
+
+    def __init__(self, name: str, tracer: Optional[Tracer],
+                 attrs: Dict[str, Any], annotation):
+        self.name, self.tracer, self.attrs = name, tracer, attrs
+        self._annotation = annotation
+        self._me = self._span = None
+
+    def __enter__(self) -> "_HostSpan":
+        if self._annotation is not None:
+            self._me = self._annotation(self.name, **self.attrs)
+            self._me.__enter__()
+        if self.tracer is not None:
+            self._span = self.tracer.open_host(self.name, self.attrs)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._span is not None:
+            self.tracer.close_host(self._span)
+        if self._me is not None:
+            self._me.__exit__(*exc)
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done."""
+        self.attrs.update(attrs)
+        if self._me is not None:
+            self._me.set_metadata(**attrs)
+
+
+def host_span(name: str, tracer: Optional[Tracer] = None,
+              **attrs) -> _HostSpan:
+    """A span around work nested on the serving thread. While the JAX
+    profiler records it is a ``TraceAnnotation`` (``attrs`` as its
+    metadata), on the device trace's clock; with a ``tracer`` it is
+    also a ``host`` span on the tracer's clock, nested in the innermost
+    open host span, under the trace id of the call named by a ``call``
+    attr (else its parent's). It reads no clock itself."""
+    annotation = _profiler_annotation()
+    if tracer is None and annotation is None:
+        return _NO_SPAN
+    return _HostSpan(name, tracer, attrs, annotation)
+
+
+__all__ = ["HOST_TRACK", "MAX_TRACE_ID", "PHASES", "Span", "Tracer",
+           "host_span"]

@@ -54,6 +54,7 @@ from repro.parallel.sharding import ParallelCtx, make_ctx
 from repro.rpc.interceptors import (ClientInterceptor,
                                     MetricsInterceptor,
                                     is_resource_exhausted)
+from repro.rpc.tracing import host_span
 from repro.serve.scheduler import Request, ServeScheduler
 
 
@@ -96,28 +97,47 @@ class ServeEngine:
     # half of the continuous-batching loop (ServeScheduler owns the
     # queueing half). Key-stream discipline is identical across all
     # three, so a preempted request resumes byte-identically.
+    # Prefill and decode are host spans ``serve.prefill`` /
+    # ``serve.decode``, their parts ``.key`` (the key split), ``.launch``
+    # (the jitted call's dispatch), ``.sample`` and ``.fetch`` (the
+    # token's copy to the host, which waits for the device).
     # ------------------------------------------------------------------
 
     def scheduler_prefill(self, req: Request) -> np.ndarray:
         """Prefill ``req`` and sample its first token; leaves the
         request's decode runtime (states, last token, key) on it."""
-        states, logits = self._prefill(
-            self.params, {"tokens": jnp.asarray(req.prompts)})
-        key = jax.random.PRNGKey(self.cfg.seed)
-        key, k0 = jax.random.split(key)
-        tok = self._sample(logits, k0)
-        req.runtime = (states, tok, key)
-        return np.asarray(tok)
+        tr = req.tracer
+        with host_span("serve.prefill", tr, request=req.id,
+                       prompt_len=req.prompt_len, call=req.call_id):
+            with host_span("serve.prefill.launch", tr):
+                states, logits = self._prefill(
+                    self.params, {"tokens": jnp.asarray(req.prompts)})
+            with host_span("serve.prefill.key", tr):
+                key = jax.random.PRNGKey(self.cfg.seed)
+                key, k0 = jax.random.split(key)
+            with host_span("serve.prefill.sample", tr):
+                tok = self._sample(logits, k0)
+            req.runtime = (states, tok, key)
+            with host_span("serve.prefill.fetch", tr):
+                return np.asarray(tok)
 
     def scheduler_decode(self, req: Request) -> np.ndarray:
         """Advance ``req`` one decode step; returns the (B,) token."""
-        states, tok, key = req.runtime
-        key, k = jax.random.split(key)
-        states, logits = self._decode_fn(req.rows)(
-            self.params, states, tok[:, None], None)
-        tok = self._sample(logits, k)
-        req.runtime = (states, tok, key)
-        return np.asarray(tok)
+        tr = req.tracer
+        with host_span("serve.decode", tr, request=req.id,
+                       position=req.prompt_len + req.generated,
+                       call=req.call_id):
+            states, tok, key = req.runtime
+            with host_span("serve.decode.key", tr):
+                key, k = jax.random.split(key)
+            with host_span("serve.decode.launch", tr):
+                states, logits = self._decode_fn(req.rows)(
+                    self.params, states, tok[:, None], None)
+            with host_span("serve.decode.sample", tr):
+                tok = self._sample(logits, k)
+            req.runtime = (states, tok, key)
+            with host_span("serve.decode.fetch", tr):
+                return np.asarray(tok)
 
     def scheduler_rebuild(self, req: Request) -> None:
         """Recompute a preempted request's runtime from its prompt and

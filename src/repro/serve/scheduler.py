@@ -29,7 +29,10 @@ suite asserts this).
 Scheduler phases are recorded as tracer spans on the serving
 endpoint's track (``waiting`` / ``prefill`` / ``decode`` /
 ``preempted``) when the scheduler is bound to an ``rpc.Server`` with a
-tracer attached — ``serve --trace`` shows per-request timelines.
+tracer attached — ``serve --trace`` shows per-request timelines. Each
+:meth:`ServeScheduler.step` is a ``serve.step`` host span
+(``rpc.tracing.host_span``), and every request carries its lifecycle
+stamps (``Request.submitted_s`` ... ``sent_s``), always on.
 """
 from __future__ import annotations
 
@@ -38,6 +41,8 @@ from collections import deque
 from typing import Any, Deque, Dict, Iterator, List, Optional
 
 import numpy as np
+
+from repro.rpc.tracing import host_span
 
 #: admission-ordering policies a ServeScheduler understands
 SCHED_POLICIES = ("fifo", "sjf")
@@ -65,11 +70,18 @@ class Request:
     decoding ``max_new_tokens`` steps. ``tokens`` holds every produced
     (B,) step vector; ``emitted`` counts how many of them the consumer
     (the rpc stream pump, or ``run``) has taken — preemption never
-    rewinds it, so re-derived tokens are not re-delivered."""
+    rewinds it, so re-derived tokens are not re-delivered.
+
+    Lifecycle stamps, on the scheduler's clock (the fabric clock when
+    bound to a server): ``submitted_s`` when queued, ``admitted_s`` at
+    its first admission, and per token ``made_s[i]`` when appended to
+    ``tokens`` and ``sent_s[i]`` when ``stream_tokens`` yields it. They
+    outlive the request's device state."""
 
     __slots__ = ("id", "prompts", "max_new_tokens", "rows",
                  "prompt_len", "tokens", "emitted", "state", "runtime",
-                 "pump", "_phase_t0")
+                 "pump", "_phase_t0", "submitted_s", "admitted_s",
+                 "made_s", "sent_s")
 
     def __init__(self, rid: int, prompts: np.ndarray,
                  max_new_tokens: int):
@@ -84,6 +96,10 @@ class Request:
         self.runtime: Any = None      # engine-owned device state
         self.pump: Any = None         # rpc.StreamPump when rpc-routed
         self._phase_t0 = 0.0
+        self.submitted_s = 0.0
+        self.admitted_s: Optional[float] = None
+        self.made_s: List[float] = []
+        self.sent_s: List[float] = []
 
     @property
     def generated(self) -> int:
@@ -92,6 +108,19 @@ class Request:
     @property
     def finished(self) -> bool:
         return self.state == FINISHED
+
+    @property
+    def tracer(self):
+        """The tracer of the rpc call this request serves, or None."""
+        srv = self.pump.server if self.pump is not None else None
+        return srv.tracer if srv is not None else None
+
+    @property
+    def call_id(self) -> Optional[int]:
+        """The rpc call this request serves (host spans of its work
+        carry it), or None."""
+        frame = self.pump.frame if self.pump is not None else None
+        return frame.call_id if frame is not None else None
 
     def blocks(self, *, block_size: int, extra: int = 0) -> int:
         """Blocks this request's ``rows`` sequences occupy with
@@ -143,7 +172,7 @@ class ServeScheduler:
         self.running: List[Request] = []
         self.counters: Dict[str, int] = {
             "submitted": 0, "admitted": 0, "finished": 0,
-            "preempted": 0, "requeued": 0, "cancelled": 0, "steps": 0,
+            "preempted": 0, "cancelled": 0, "steps": 0,
             "peak_running": 0, "peak_waiting": 0,
         }
         self._server = None          # rpc.Server this endpoint serves on
@@ -161,6 +190,9 @@ class ServeScheduler:
             return self._server.clock()
         return time.perf_counter()
 
+    def _tracer(self):
+        return self._server.tracer if self._server is not None else None
+
     def _span(self, req: Request, name: str, t0: float, t1: float,
               **attrs) -> None:
         srv = self._server
@@ -171,12 +203,15 @@ class ServeScheduler:
             tracer.server_span(req.pump.frame, srv.endpoint, name,
                                t0, t1, request=req.id, **attrs)
 
-    def _enter_phase(self, req: Request, state: str) -> None:
+    def _enter_phase(self, req: Request, state: str,
+                     t: Optional[float] = None) -> None:
         req.state = state
-        req._phase_t0 = self._now()
+        req._phase_t0 = self._now() if t is None else t
 
-    def _close_phase(self, req: Request, name: str, **attrs) -> None:
-        self._span(req, name, req._phase_t0, self._now(), **attrs)
+    def _close_phase(self, req: Request, name: str,
+                     t: Optional[float] = None) -> None:
+        self._span(req, name, req._phase_t0,
+                   self._now() if t is None else t)
 
     # intake -----------------------------------------------------------
     def submit(self, prompts: np.ndarray,
@@ -193,6 +228,7 @@ class ServeScheduler:
         req = Request(self._next_id, prompts, mnt)
         self._next_id += 1
         self._enter_phase(req, WAITING)
+        req.submitted_s = req._phase_t0
         self.waiting.append(req)
         self.counters["submitted"] += 1
         self.counters["peak_waiting"] = max(
@@ -260,6 +296,14 @@ class ServeScheduler:
         """One tick of the continuous batch: admit/resume what fits,
         preempt on budget exhaustion, then advance every running
         request one token. Returns the number of tokens produced."""
+        admitted = self.counters["admitted"]
+        with host_span("serve.step", self._tracer()) as span:
+            produced = self._step()
+            span.set(running=produced,
+                     admitted=self.counters["admitted"] - admitted)
+        return produced
+
+    def _step(self) -> int:
         fresh: List[Request] = []
         # join: policy order, bounded by max_batch + kv budget (the
         # selected candidate not fitting blocks further admission —
@@ -271,16 +315,21 @@ class ServeScheduler:
             req = self.waiting[idx]
             del self.waiting[idx]
             resumed = req.state == PREEMPTED
-            self._close_phase(req, WAITING if not resumed else PREEMPTED)
             t0 = self._now()
+            self._close_phase(req, WAITING if not resumed else PREEMPTED,
+                              t0)
             if resumed:
                 self.engine.scheduler_rebuild(req)
+                t1 = self._now()
             else:
+                if req.admitted_s is None:
+                    req.admitted_s = t0
                 tok = self.engine.scheduler_prefill(req)
+                t1 = self._now()
                 req.tokens.append(tok)
-            self._span(req, "prefill", t0, self._now(),
-                       resumed=resumed)
-            self._enter_phase(req, RUNNING)
+                req.made_s.append(t1)
+            self._span(req, "prefill", t0, t1, resumed=resumed)
+            self._enter_phase(req, RUNNING, t1)
             self.running.append(req)
             self.counters["admitted"] += 1
             fresh.append(req)
@@ -298,14 +347,15 @@ class ServeScheduler:
             self._enter_phase(victim, PREEMPTED)
             self.waiting.appendleft(victim)
             self.counters["preempted"] += 1
-            self.counters["requeued"] += 1
         produced = 0
         for req in list(self.running):
             if req not in fresh:     # joiners produced theirs at prefill
-                req.tokens.append(self.engine.scheduler_decode(req))
+                tok = self.engine.scheduler_decode(req)
+                req.tokens.append(tok)
+                req.made_s.append(self._now())
             produced += 1
             if req.generated >= req.max_new_tokens:
-                self._close_phase(req, "decode")
+                self._close_phase(req, "decode", req.made_s[-1])
                 self.running.remove(req)
                 req.runtime = None
                 req.state = FINISHED
@@ -325,6 +375,7 @@ class ServeScheduler:
                 if req.emitted < len(req.tokens):
                     tok = req.tokens[req.emitted]
                     req.emitted += 1
+                    req.sent_s.append(self._now())
                     yield tok
                 elif req.finished:
                     return

@@ -164,17 +164,24 @@ def test_chrome_export_shape(tmp_path):
     doc = json.loads(out.read_text())
     ev = doc["traceEvents"]
     meta = [e for e in ev if e["ph"] == "M"]
-    # process name + one named track per endpoint that recorded spans
+    # process name + one named track per endpoint that recorded spans,
+    # and one for the flush loop's host spans
     assert {m["name"] for m in meta} == {"process_name", "thread_name"}
     assert {m["tid"] for m in meta if m["name"] == "thread_name"} \
-        == {0, 1}
+        == {0, 1, rpc.tracing.HOST_TRACK}
     xs = [e for e in ev if e["ph"] == "X"]
     assert xs
     for e in xs:
         assert e["dur"] >= 0 and e["pid"] == 0
-        assert e["args"]["trace_id"] >= 1
+        if e["cat"] == "host":
+            # a flight's delivery serves no single call: untraced (0)
+            assert e["tid"] == rpc.tracing.HOST_TRACK
+            assert e["name"] in ("rpc.deliver", "rpc.complete")
+            assert e["args"]["trace_id"] == 0
+        else:
+            assert e["args"]["trace_id"] >= 1
     assert {e["cat"] for e in xs} >= {"call", "attempt", "phase",
-                                      "wire", "server"}
+                                      "wire", "server", "host"}
     # file-like export produces the same document
     buf = io.StringIO()
     tracer.export_chrome(buf)
