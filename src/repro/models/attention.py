@@ -46,8 +46,13 @@ def init_attention(key, cfg: ModelConfig, att: AttentionConfig,
 
 
 class KVCache(NamedTuple):
-    k: jax.Array          # (B, S_cache, n_kv, d_head)
-    v: jax.Array          # (B, S_cache, n_kv, d_head)
+    # (B, n_kv * d_head, S_cache): the slots minor-most, as the decode
+    # step's attention reads them, so the default layout is the one its
+    # loop works in, and heads folded so a TPU tiles it unpadded (n_kv 20
+    # would pad to 24 as a dim of its own); stacked over periods as a
+    # decode state
+    k: jax.Array
+    v: jax.Array
     # the *global* write cursor (tokens seen so far), traced scalar
     index: jax.Array
 
@@ -165,9 +170,18 @@ def attention_forward_flash(p: dict, att: AttentionConfig, x: jax.Array,
 
 
 def attention_decode(p: dict, att: AttentionConfig, x: jax.Array,
-                     cache: KVCache, *, window: Optional[int]
+                     cache: KVCache, *, window: Optional[int],
+                     layer: Optional[jax.Array] = None
                      ) -> tuple[jax.Array, KVCache]:
-    """One-token decode. x: (B,1,d); cache k/v: (B,Sc,KV,dh).
+    """One-token decode. x: (B,1,d); cache k/v: (B,KV*dh,Sc), or, with
+    ``layer``, the stack (L,B,KV*dh,Sc) of every period's cache, of
+    which this layer's is ``layer``.
+
+    The new token's K/V is written with one ``dynamic_update_slice`` at
+    ``(layer, 0, 0, slot)`` and the layer read back by index, so a stack
+    carried through the period scan (and donated to the step) is updated
+    in place: no copy of a layer's cache, let alone of the stack. A
+    single layer's cache is a stack of one.
 
     For windowed layers the cache is a ring buffer of size >= window; for
     full layers Sc is the max context. ``cache.index`` is the global
@@ -175,15 +189,23 @@ def attention_decode(p: dict, att: AttentionConfig, x: jax.Array,
     """
     B, S1, d = x.shape
     assert S1 == 1
-    Sc = cache.k.shape[1]
+    stacked = layer is not None
+    ks, vs = (cache.k, cache.v) if stacked else (cache.k[None], cache.v[None])
+    li = layer if stacked else 0
+    Sc = ks.shape[3]
+    KV, dh = att.n_kv_heads, att.d_head
     pos = jnp.full((1,), cache.index, jnp.int32)
     q, k_new, v_new = _qkv(p, att, x, pos)
 
     slot = cache.index % Sc  # ring-buffer slot (== index when Sc >= context)
-    k = jax.lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype),
-                                     (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype),
-                                     (0, slot, 0, 0))
+    at = (li, 0, 0, slot)
+    ks = jax.lax.dynamic_update_slice(
+        ks, k_new.reshape(1, B, KV * dh, 1).astype(ks.dtype), at)
+    vs = jax.lax.dynamic_update_slice(
+        vs, v_new.reshape(1, B, KV * dh, 1).astype(vs.dtype), at)
+    k = jax.lax.dynamic_index_in_dim(ks, li, 0, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(vs, li, 0, keepdims=False)
+    k, v = k.reshape(B, KV, dh, Sc), v.reshape(B, KV, dh, Sc)
 
     # Position of every cache slot, reconstructed from the ring layout:
     # the most recent position p <= index with p % Sc == slot.
@@ -204,7 +226,7 @@ def attention_decode(p: dict, att: AttentionConfig, x: jax.Array,
     # K/V stay in cache dtype (bf16); accumulate in fp32 — upcasting the
     # cache would double the HBM traffic of the token's cache scan (§Perf
     # hypothesis B2).
-    s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(k.dtype), k,
+    s = jnp.einsum("bkgd,bkds->bkgs", qg.astype(k.dtype), k,
                    preferred_element_type=jnp.float32) * scale
     if att.logit_softcap is not None:
         s = jnp.tanh(s / att.logit_softcap) * att.logit_softcap
@@ -212,15 +234,17 @@ def attention_decode(p: dict, att: AttentionConfig, x: jax.Array,
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - jax.lax.stop_gradient(m))
     probs = e / jnp.sum(e, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v.dtype), v,
+    out = jnp.einsum("bkgs,bkds->bkgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32).astype(v.dtype)
     out = out.reshape(B, 1, att.n_heads * att.d_head) @ p["wo"]
-    return out, KVCache(k=k, v=v, index=cache.index + 1)
+    if not stacked:
+        ks, vs = ks[0], vs[0]
+    return out, KVCache(k=ks, v=vs, index=cache.index + 1)
 
 
 def init_cache(att: AttentionConfig, batch: int, max_seq: int,
                window: Optional[int], dtype) -> KVCache:
     Sc = min(max_seq, window) if window is not None else max_seq
-    shape = (batch, Sc, att.n_kv_heads, att.d_head)
+    shape = (batch, att.n_kv_heads * att.d_head, Sc)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    index=jnp.zeros((), jnp.int32))

@@ -206,9 +206,12 @@ def _apply_position(ctx: ParallelCtx, cfg: ModelConfig, pos: int, p: Params,
                     x: jax.Array, state: Optional[Params], mode: str,
                     positions: jax.Array, compute_dtype,
                     max_seq: Optional[int] = None,
-                    use_flash: bool = False, use_rwkv_k: bool = False
+                    use_flash: bool = False, use_rwkv_k: bool = False,
+                    layer: Optional[jax.Array] = None
                     ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """One layer. Returns (x, new_state, aux_loss)."""
+    """One layer. Returns (x, new_state, aux_loss). In decode, an
+    attention state whose K/V are stacked over periods comes with the
+    ``layer`` it belongs to (``attention_decode``)."""
     kind = cfg.layer_pattern[pos]
     window = cfg.window_at(pos)
     aux = jnp.zeros((), jnp.float32)
@@ -222,7 +225,8 @@ def _apply_position(ctx: ParallelCtx, cfg: ModelConfig, pos: int, p: Params,
                else attn_lib.attention_forward)
         if mode == "decode":
             h, cache = attn_lib.attention_decode(p["mixer"], att, h,
-                                                 mixer_state, window=window)
+                                                 mixer_state, window=window,
+                                                 layer=layer)
             new_state = {"mixer": cache}
         elif mode == "prefill":
             h, kv = fwd(p["mixer"], att, h, positions, window=window,
@@ -279,15 +283,18 @@ def _constrain_act(ctx: ParallelCtx, x: jax.Array) -> jax.Array:
 
 def _cache_from_prefill(kv, window: Optional[int],
                         max_seq: int) -> KVCache:
-    """Lay prefill K/V out as a ring buffer of Sc slots (slot = pos % Sc)."""
+    """Lay prefill K/V out as a ring buffer of Sc slots (slot = pos % Sc),
+    as the decode cache holds them: (B, KV*dh, Sc)."""
     k, v = kv
     B, S, KV, dh = k.shape
+    k = k.reshape(B, S, KV * dh).swapaxes(1, 2)
+    v = v.reshape(B, S, KV * dh).swapaxes(1, 2)
     Sc = min(max_seq, window) if window is not None else max_seq
     if Sc < S:      # windowed: keep the last Sc positions, ring layout
-        k = jnp.roll(k[:, -Sc:], S % Sc, axis=1)
-        v = jnp.roll(v[:, -Sc:], S % Sc, axis=1)
+        k = jnp.roll(k[..., -Sc:], S % Sc, axis=2)
+        v = jnp.roll(v[..., -Sc:], S % Sc, axis=2)
     elif Sc > S:    # room to grow: unwritten slots are masked by position
-        pad = ((0, 0), (0, Sc - S), (0, 0), (0, 0))
+        pad = ((0, 0), (0, 0), (0, Sc - S))
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     return KVCache(k=k, v=v, index=jnp.asarray(S, jnp.int32))
 
@@ -305,25 +312,55 @@ def _embed_in(ctx, cfg, params, tokens, embeds, compute_dtype):
     return _constrain_act(ctx, x)
 
 
+def _take_kv(state: Params) -> Tuple[Optional[Tuple], Params]:
+    """A position's decode state -> (its K/V stacks, or None where it
+    holds no KV cache, and the state without them). The stacks ride the
+    period scan's carry, each layer writing its token in place; every
+    other leaf (cache indices, recurrent states) stays per period in the
+    scan's xs/ys."""
+    m = state["mixer"]
+    if not isinstance(m, KVCache):
+        return None, state
+    return (m.k, m.v), {**state, "mixer": m._replace(k=None, v=None)}
+
+
+def _put_kv(kv: Tuple, state: Params) -> Params:
+    """Inverse of ``_take_kv``."""
+    k, v = kv
+    return {**state, "mixer": state["mixer"]._replace(k=k, v=v)}
+
+
 def _scan_periods(ctx, acfg, params, x, states, mode, positions,
                   compute_dtype, max_seq=None):
     cfg = acfg.model
     aux0 = jnp.zeros((), jnp.float32)
+    kv = {}
+    if mode == "decode":
+        taken = {n: _take_kv(st) for n, st in states.items()}
+        kv = {n: t[0] for n, t in taken.items() if t[0] is not None}
+        states = {n: t[1] for n, t in taken.items()}
 
-    def period_body(x, per_params, per_states):
+    def period_body(x, per_params, per_states, kv, layer):
         new_states = {} if mode != "train" else None
+        kv = dict(kv)
         aux_sum = jnp.zeros((), jnp.float32)
         for i in range(cfg.pattern_period):
-            st = per_states[f"pos{i}"] if per_states is not None else None
-            x, ns, aux = _apply_position(ctx, cfg, i, per_params[f"pos{i}"],
+            name = f"pos{i}"
+            st = per_states[name] if per_states is not None else None
+            if name in kv:
+                st = _put_kv(kv[name], st)
+            x, ns, aux = _apply_position(ctx, cfg, i, per_params[name],
                                          x, st, mode, positions,
                                          compute_dtype, max_seq,
                                          acfg.train.use_flash_kernel,
-                                         acfg.train.use_rwkv_kernel)
+                                         acfg.train.use_rwkv_kernel,
+                                         layer)
             aux_sum = aux_sum + aux
+            if name in kv:
+                kv[name], ns = _take_kv(ns)
             if new_states is not None:
-                new_states[f"pos{i}"] = ns
-        return x, new_states, aux_sum
+                new_states[name] = ns
+        return x, new_states, aux_sum, kv
 
     use_remat = (mode == "train" and acfg.train.remat)
     if use_remat:
@@ -335,13 +372,16 @@ def _scan_periods(ctx, acfg, params, x, states, mode, positions,
 
     if acfg.train.scan_layers and cfg.n_periods > 1:
         def scan_body(carry, xs):
-            x, aux = carry
-            per_params, per_states = xs
-            x, ns, aux_p = period_body(x, per_params, per_states)
-            return (x, aux + aux_p), ns
+            x, aux, kv = carry
+            per_params, per_states, layer = xs
+            x, ns, aux_p, kv = period_body(x, per_params, per_states, kv,
+                                           layer)
+            return (x, aux + aux_p, kv), ns
 
-        xs = (params["blocks"], states if mode != "train" else None)
-        (x, aux), new_states = jax.lax.scan(scan_body, (x, aux0), xs)
+        xs = (params["blocks"], states if mode != "train" else None,
+              jnp.arange(cfg.n_periods, dtype=jnp.int32))
+        (x, aux, kv), new_states = jax.lax.scan(scan_body, (x, aux0, kv),
+                                                xs)
     else:
         aux = aux0
         new_states_list = []
@@ -350,12 +390,16 @@ def _scan_periods(ctx, acfg, params, x, states, mode, positions,
                                       params["blocks"])
             per_states = (jax.tree.map(lambda a, li=li: a[li], states)
                           if states is not None else None)
-            x, ns, aux_p = period_body(x, per_params, per_states)
+            x, ns, aux_p, kv = period_body(x, per_params, per_states, kv,
+                                           li)
             aux = aux + aux_p
             new_states_list.append(ns)
         new_states = (jax.tree.map(lambda *xs: jnp.stack(xs),
                                    *new_states_list)
                       if mode != "train" else None)
+    if mode == "decode":
+        new_states = {n: _put_kv(kv[n], st) if n in kv else st
+                      for n, st in new_states.items()}
     return x, new_states, aux
 
 
@@ -481,8 +525,8 @@ def state_logical_axes(acfg: ArchConfig, batch: int) -> Params:
         kind = cfg.layer_pattern[pos]
         if kind == "attn":
             return {"mixer": KVCache(
-                k=LP("layers", b_ax, s_ax, None, None),
-                v=LP("layers", b_ax, s_ax, None, None),
+                k=LP("layers", b_ax, None, s_ax),
+                v=LP("layers", b_ax, None, s_ax),
                 index=LP("layers"))}
         if kind == "mamba":
             return {"mixer": {"h": LP("layers", b_ax, "heads", None, None),

@@ -56,35 +56,57 @@ def test_one_train_step(arch):
     assert any(jax.tree.leaves(changed))
 
 
-@pytest.mark.parametrize("arch", [a for a in list_archs()
-                                  if not get_reduced_config(a).model
-                                  .is_encoder])
-def test_prefill_decode_consistency(arch):
-    cfg = get_reduced_config(arch)
+def _check_prefill_decode(cfg, prompt: int, steps: int, max_seq: int):
+    """Prefill ``prompt`` tokens, decode ``steps`` more one at a time,
+    and hold each step's logits to the full forward's at that position."""
     m = cfg.model
     key = jax.random.PRNGKey(1)
     params = init_params(key, cfg)
+    n = prompt + steps
     if m.frontend:
-        emb = jax.random.normal(key, (B, S + 2, m.d_model))
+        emb = jax.random.normal(key, (B, n, m.d_model))
         fk = dict(embeds=emb)
-        pk = dict(embeds=emb[:, :S])
-        dks = [dict(embeds=emb[:, S + i:S + i + 1]) for i in range(2)]
+        pk = dict(embeds=emb[:, :prompt])
+        dks = [dict(embeds=emb[:, prompt + i:prompt + i + 1])
+               for i in range(steps)]
     else:
-        toks = jax.random.randint(key, (B, S + 2), 0, m.vocab_size)
+        toks = jax.random.randint(key, (B, n), 0, m.vocab_size)
         fk = dict(tokens=toks)
-        pk = dict(tokens=toks[:, :S])
-        dks = [dict(tokens=toks[:, S + i:S + i + 1]) for i in range(2)]
+        pk = dict(tokens=toks[:, :prompt])
+        dks = [dict(tokens=toks[:, prompt + i:prompt + i + 1])
+               for i in range(steps)]
     h_full, _, _ = forward(NO_MESH, cfg, params, mode="train", **fk)
     _, states, _ = forward(NO_MESH, cfg, params, mode="prefill",
-                           max_seq=S + 4, **pk)
-    for i in range(2):
-        ref = logits_fn(NO_MESH, cfg, params, h_full)[:, S + i]
+                           max_seq=max_seq, **pk)
+    for i in range(steps):
+        ref = logits_fn(NO_MESH, cfg, params, h_full)[:, prompt + i]
         h_dec, states, _ = forward(NO_MESH, cfg, params, mode="decode",
                                    states=states, **dks[i])
         got = logits_fn(NO_MESH, cfg, params, h_dec)[:, 0]
         err = float(jnp.max(jnp.abs(ref - got))
                     / (jnp.max(jnp.abs(ref)) + 1e-9))
-        assert err < 2e-3, (arch, i, err)
+        assert err < 2e-3, (cfg.model.name, i, err)
+
+
+@pytest.mark.parametrize("arch", [a for a in list_archs()
+                                  if not get_reduced_config(a).model
+                                  .is_encoder])
+def test_prefill_decode_consistency(arch):
+    _check_prefill_decode(get_reduced_config(arch), S, 2, S + 4)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_decode_past_ring_wrap(scan_layers):
+    """gemma2's local layers hold a ring of ``window`` slots: a 60-token
+    prompt and 8 decoded tokens wrap it, in the period scan and in the
+    unrolled loop alike (two periods, so each carries a stack of two)."""
+    cfg = get_reduced_config("gemma2-9b", n_layers=4)
+    assert cfg.model.n_periods == 2
+    window = cfg.model.window_at(0)
+    assert window is not None and 60 < window < 60 + 8
+    cfg = cfg.replace(train=dataclasses.replace(cfg.train,
+                                                scan_layers=scan_layers))
+    _check_prefill_decode(cfg, 60, 8, 72)
 
 
 @pytest.mark.parametrize("arch", ["qwen3-8b", "rwkv6-1.6b"])

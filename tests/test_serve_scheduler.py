@@ -356,6 +356,46 @@ def test_kv_exhaustion_over_rpc_preempts_requeues_and_traces(eng):
         assert phase in names, (phase, sorted(names))
 
 
+def test_rebuild_after_preemption_gives_the_dropped_states(eng,
+                                                          monkeypatch):
+    """A preempted request's state, rebuilt by replaying its prefill and
+    decode steps, is byte for byte the state it held when it was
+    dropped: the caches written in place, their indices, the last token
+    and the key."""
+    import jax
+    held, checked = {}, []
+
+    def on_host(runtime):
+        return [np.asarray(a).tobytes() for a in jax.tree.leaves(runtime)]
+
+    def keeping(op):
+        def run(req):
+            out = op(req)
+            held[req.id] = on_host(req.runtime)
+            return out
+        return run
+
+    rebuild = eng.scheduler_rebuild
+
+    def checked_rebuild(req):
+        dropped = held[req.id]
+        rebuild(req)
+        checked.append(on_host(req.runtime) == dropped)
+
+    monkeypatch.setattr(eng, "scheduler_prefill",
+                        keeping(eng.scheduler_prefill))
+    monkeypatch.setattr(eng, "scheduler_decode",
+                        keeping(eng.scheduler_decode))
+    monkeypatch.setattr(eng, "scheduler_rebuild", checked_rebuild)
+    sched = eng.make_scheduler(max_batch=4, kv_blocks=21, block_size=1)
+    reqs = [sched.submit(_rng_prompts(eng, 1, 8, 8 + i), 4)
+            for i in range(2)]
+    while not all(r.finished for r in reqs):
+        sched.step()
+    assert sched.counters["preempted"] >= 1
+    assert checked and all(checked)
+
+
 def test_unary_over_rpc_shares_the_endpoint_scheduler(eng):
     from repro import rpc as rpclib
     from repro.serve.engine import serve_stub

@@ -10,6 +10,7 @@ test workers all import this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,6 +117,90 @@ def test_qwen15_4b_prefill_fits_one_chip(one_chip):
     assert mem.argument_size_in_bytes < V5E_HBM_BYTES
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < V5E_HBM_BYTES
+
+
+#: what ``bench/configs/qwen15-4b-serve.json`` serves: one row, 2304 slots
+QWEN_MAX_SEQ = 2304
+#: one layer's K (or V) cache at that size: 20 heads x 128 x 2304 slots
+QWEN_SLICE = QWEN_MAX_SEQ * 20 * 128
+#: what HLO text prints of an instruction outside a fused computation
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
+
+
+def _scheduled_ops(text):
+    """(name, opcode, [(elements, in HBM)]) of every instruction that is
+    not inside a fused computation. A layout with a memory space
+    (``S(1)``) lives on the chip, not in HBM."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    comp, ops = None, []
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            m = re.match(r"(?:ENTRY )?%([\w.\-]+)", line)
+            comp = m.group(1) if m else None
+            continue
+        m = _INSTR.match(line)
+        if m is None or comp in fused:
+            continue
+        outs = [(int(np.prod([int(d) for d in dims.split(",") if d])),
+                 "S(" not in layout)
+                for _, dims, layout in _SHAPE.findall(m.group(2))]
+        ops.append((m.group(1), m.group(3), outs))
+    return ops
+
+
+@pytest.fixture(scope="module")
+def qwen_serving(one_chip):
+    """qwen1.5-4b's decode step as the serve engine runs it (bf16,
+    B = 1, 2304 slots), compiled for one v5e, and its prefill."""
+    from repro.launch.steps import make_decode_step, make_prefill_step
+    from repro.models import init_params, init_states
+    from repro.parallel import NO_MESH
+    from repro.serve.engine import serving_config
+    acfg = serving_config(get_config("qwen1.5-4b"))
+    on_chip = lambda s: _sds(s.shape, s.dtype, one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        functools.partial(init_params, acfg=acfg), jax.random.PRNGKey(0)))
+    states = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_states(NO_MESH, acfg, 1, QWEN_MAX_SEQ)))
+    decode = make_decode_step(NO_MESH, acfg, 1).lower(
+        params, states, _sds((1, 1), jnp.int32, one_chip), None).compile()
+    prefill = make_prefill_step(NO_MESH, acfg, max_seq=QWEN_MAX_SEQ)
+    return decode, prefill, params
+
+
+def test_qwen15_4b_decode_updates_its_cache_in_place(qwen_serving,
+                                                     one_chip):
+    """No copy of a layer's cache or more, and nothing of that size
+    written to HBM but the in-place update of each stacked cache; no
+    temporary of a cache's size; the caches unpadded, in the layout the
+    prefill emits them in, the same in and out."""
+    decode, prefill, params = qwen_serving
+    bulky = [(name, op, outs) for name, op, outs in
+             _scheduled_ops(decode.as_text())
+             if any(n >= QWEN_SLICE for n, _ in outs)
+             and op not in ("parameter", "get-tuple-element", "bitcast",
+                            "tuple", "while")]
+    assert not [b for b in bulky if b[0].startswith("copy")], bulky
+    in_hbm = [b for b in bulky if any(hbm for _, hbm in b[2])]
+    assert [op for _, op, _ in in_hbm] == ["dynamic-update-slice"] * 2, \
+        in_hbm                                          # K and V
+    assert decode.memory_analysis().temp_size_in_bytes < 64 << 20
+
+    formats = decode.input_formats[0][1]
+    assert decode.output_formats[0] == formats
+    mixer = formats["pos0"]["mixer"]
+    cache_bytes = 0
+    for f in (mixer.k, mixer.v):
+        arg = _sds((40, 1, 2560, QWEN_MAX_SEQ), jnp.bfloat16, one_chip)
+        ident = jax.jit(lambda a: a, in_shardings=f, out_shardings=f)
+        cache_bytes += ident.lower(arg).compile().memory_analysis() \
+            .argument_size_in_bytes
+    assert cache_bytes == 2 * 471_859_200        # 2 x 40 x 2304 x 2560 x 2 B
+
+    tokens = _sds((1, 2048), jnp.int32, one_chip)
+    emitted = prefill.lower(params, {"tokens": tokens}).compile()
+    assert emitted.output_formats[0] == formats
 
 
 @pytest.mark.parametrize("serialized", [False, True])
